@@ -12,20 +12,10 @@ import jax
 def build_mesh(shape, axes):
     """The one mesh-construction path (every builder here and
     ``repro.pipeline.spmd.stage_mesh`` routes through it — construct
-    meshes nowhere else).
-
-    Wraps jax.make_mesh across versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer JAX releases; all
-    axes here are Auto, which is also the older default."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    meshes nowhere else).  Every axis is Auto: the compiler
+    propagates shardings, as the pipeline and dry-run code expect."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-#: Backward-compatible alias (pre-dedup private name).
-_make_mesh = build_mesh
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -22,7 +22,6 @@ from typing import Dict, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models import blocks as blk
@@ -120,11 +119,11 @@ def make_pipeline_fn(cfg: ModelConfig, mesh, *, stage_axis: str = "stage",
 
     # model-parallel sub-sharding of the per-stage tiles is delegated to
     # pjit on the caller side; the shard_map here only owns stage_axis.
-    fn = shard_map(
+    fn = jax.shard_map(
         pipeline, mesh=mesh,
         in_specs=(P(stage_axis), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

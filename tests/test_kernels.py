@@ -160,3 +160,14 @@ def test_ssd_matches_model_chunked_form():
     y_model, _ = ssd_chunked(x, dt, A, B_, C, chunk=64)
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_model),
                                atol=5e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("op,nargs", [("flash_attention", 3),
+                                      ("decode_attention", 4),
+                                      ("ssd_scan", 5)])
+def test_unknown_impl_is_refused(op, nargs):
+    """No impl is picked from the backend: a name outside ops.IMPLS
+    ("auto" among them) raises instead of falling back."""
+    args = [jnp.zeros((1, 1, 8, 8))] * nargs
+    with pytest.raises(ValueError, match="impl="):
+        getattr(ops, op)(*args, impl="auto")
