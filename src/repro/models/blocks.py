@@ -106,11 +106,17 @@ def block_forward(params, cfg: ModelConfig, x: jnp.ndarray,
     for i, (mixer, ffn) in enumerate(_sublayer_kinds(cfg)):
         sub = params[f"sub{i}"]
         h = rms_norm(x, sub["ln1"]["scale"], cfg.rms_eps)
+        # Scope names label the mixer's and FFN's operations in the HLO
+        # metadata, for per-scope device time.
         if mixer == "attn":
-            x = x + attn_lib.attention_forward(sub["mixer"], cfg, h, positions)
+            with jax.named_scope("attention"):
+                x = x + attn_lib.attention_forward(sub["mixer"], cfg, h,
+                                                   positions)
         else:
-            x = x + mamba_lib.mamba_forward(sub["mixer"], cfg, h)
-        delta, st = _apply_ffn(sub, cfg, ffn, x)
+            with jax.named_scope("ssd"):
+                x = x + mamba_lib.mamba_forward(sub["mixer"], cfg, h)
+        with jax.named_scope("moe" if ffn == "moe" else "mlp"):
+            delta, st = _apply_ffn(sub, cfg, ffn, x)
         if delta is not None:
             x = x + delta
         stats = {k: stats[k] + st[k] for k in stats}

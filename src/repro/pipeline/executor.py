@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import blocks as blk
 from repro.models.layers import embed, rms_norm, unembed
+from repro.telemetry.spans import span
 # Canonical home is the typed serving-error hierarchy
 # (repro.util.errors); re-exported here for backward compatibility.
 from repro.util.errors import MixedSequenceLengthError  # noqa: F401
@@ -55,22 +56,27 @@ class LocalPipelineExecutor:
         self.params = params
         cfg_ = cfg
 
+        # The named scopes label each program's operations in the
+        # compiled HLO's metadata (op_name), for per-scope device time.
         @jax.jit
         def stage_fn(params, x, positions, lo, hi):
             def body(i, h):
                 bp = jax.tree.map(lambda p: p[i], params["blocks"])
                 h, _ = blk.block_forward(bp, cfg_, h, positions)
                 return h
-            return jax.lax.fori_loop(lo, hi, body, x)
+            with jax.named_scope("stage"):
+                return jax.lax.fori_loop(lo, hi, body, x)
 
         @jax.jit
         def embed_fn(params, tokens):
-            return embed(params["embed"], tokens)
+            with jax.named_scope("embed"):
+                return embed(params["embed"], tokens)
 
         @jax.jit
         def head_fn(params, x):
-            x = rms_norm(x, params["final_norm"]["scale"], cfg_.rms_eps)
-            return unembed(params["head"], x)
+            with jax.named_scope("head"):
+                x = rms_norm(x, params["final_norm"]["scale"], cfg_.rms_eps)
+                return unembed(params["head"], x)
 
         self._stage_fn = stage_fn
         self._embed_fn = embed_fn
@@ -120,11 +126,12 @@ class LocalPipelineExecutor:
         of the ``lo``/``hi`` runtime arguments — and its jitter — never
         lands inside a stage-time measurement the scheduler consumes.
         """
-        bounds = [(jnp.int32(lo), jnp.int32(hi))
-                  for lo, hi in stage_bounds(config)]
-        for lo, hi in bounds:
-            lo.block_until_ready()
-            hi.block_until_ready()
+        with span("executor.bounds", syncs=2 * len(config)):
+            bounds = [(jnp.int32(lo), jnp.int32(hi))
+                      for lo, hi in stage_bounds(config)]
+            for lo, hi in bounds:
+                lo.block_until_ready()
+                hi.block_until_ready()
         return bounds
 
     def embed_tokens(self, tokens: jnp.ndarray) -> tuple:
@@ -133,10 +140,12 @@ class LocalPipelineExecutor:
         Blocks until the embedding is on device so the first stage's
         measured time never includes the embed dispatch.
         """
-        B, S = tokens.shape
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        x = self._embed_fn(self.params, tokens)
-        x.block_until_ready()
+        with span("executor.embed", syncs=1):
+            B, S = tokens.shape
+            positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
+                                         (B, S))
+            x = self._embed_fn(self.params, tokens)
+            x.block_until_ready()
         return x, positions
 
     def run_stages(self, x: jnp.ndarray, positions: jnp.ndarray,
@@ -162,21 +171,26 @@ class LocalPipelineExecutor:
         times = np.zeros(hi_stage - lo_stage)
         for s in range(lo_stage, hi_stage):
             lo, hi = bounds[s]
-            t0 = time.perf_counter()
-            x = self._stage_fn(self.params, x, positions, lo, hi)
-            x.block_until_ready()
-            dt = time.perf_counter() - t0
+            with span("executor.stage", stage=s, blocks=int(config[s]),
+                      syncs=1):
+                t0 = time.perf_counter()
+                x = self._stage_fn(self.params, x, positions, lo, hi)
+                x.block_until_ready()
+                dt = time.perf_counter() - t0
             if slowdowns is not None and slowdowns[s] > 1.0:
                 extra = dt * (slowdowns[s] - 1.0)
-                time.sleep(extra)
+                with span("executor.interference", stage=s,
+                          factor_pct=int(round(100 * slowdowns[s]))):
+                    time.sleep(extra)
                 dt += extra
             times[s - lo_stage] = dt
         return x, times
 
     def head(self, x: jnp.ndarray) -> jnp.ndarray:
         """Final norm + unembed, blocked until ready."""
-        logits = self._head_fn(self.params, x)
-        logits.block_until_ready()
+        with span("executor.head", syncs=1):
+            logits = self._head_fn(self.params, x)
+            logits.block_until_ready()
         return logits
 
     def run_query(self, tokens: jnp.ndarray, config: Sequence[int],

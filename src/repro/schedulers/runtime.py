@@ -23,12 +23,17 @@ configuration the query must run with plus whether it is a serial
 
 Accounting matches the paper's: ``num_rebalances`` counts phases that
 cost at least one serial query (the oracle is free), ``total_trials`` /
-``mitigation_lengths`` mirror Fig. 8's exploration overhead.
+``mitigation_lengths`` mirror Fig. 8's exploration overhead.  Where
+those counters count, a phase's start and its commit are also marked on
+the profiler's clock (``rebalance.detect``, ``rebalance.commit``;
+docs/TELEMETRY.md "Spans").
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import TYPE_CHECKING, List, Optional, Sequence
+
+from repro.telemetry.spans import mark
 
 if TYPE_CHECKING:  # annotation-only: keeps repro.core <-> schedulers acyclic
     from repro.core.pipeline_state import StageTimeSource
@@ -161,6 +166,7 @@ class RebalanceRuntime:
                 self.explorer = self.policy.make_explorer(self.config)
             if self._serial_phase:
                 self.num_rebalances += 1
+                mark("rebalance.detect")
 
         if not self._serial_phase:
             # Instant policy: commit within this poll; the query itself
@@ -242,6 +248,9 @@ class RebalanceRuntime:
             # log no Trial but still serialized a query.
             self.total_trials += self._phase_steps
             self.mitigation_lengths.append(self._phase_steps)
+            mark("rebalance.commit",
+                 changed=int(list(res.config) != self.config),
+                 trials=self._phase_steps)
         self.explorer = None
         self._phase_steps = 0
         self.config = list(res.config)

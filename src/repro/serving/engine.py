@@ -42,6 +42,7 @@ batching & length buckets").
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -66,6 +67,7 @@ from repro.schedulers.defaults import DEFAULT_ALPHA, MEASURED_DETECTOR_MODE
 from repro.schedulers.registry import make_scheduler
 from repro.util.errors import QueryError
 from repro.schedulers.runtime import RebalanceRuntime, RuntimeStep
+from repro.telemetry.spans import span
 from repro.workloads import (
     BatchRecord,
     DispatchRecord,
@@ -255,10 +257,11 @@ class _LiveQueryExecutor:
         else:
             tokens = self.queries[q]
         finish = self._measure(step.config, eng._block_times is None)
-        t0 = time.perf_counter()
-        _, st = eng.executor.run_query(tokens, step.config,
-                                       slowdowns=self._slow)
-        latency = time.perf_counter() - t0
+        with span("engine.query", query=int(q), trial=int(step.serial)):
+            t0 = time.perf_counter()
+            _, st = eng.executor.run_query(tokens, step.config,
+                                           slowdowns=self._slow)
+            latency = time.perf_counter() - t0
         tmax = finish(st)
         coll_frac = 0.0
         if eng.mesh is not None and step.mesh is not None:
@@ -286,10 +289,12 @@ class _LiveQueryExecutor:
         eng.executor.ensure_warm(sum(int(t.shape[0]) for t in batch),
                                  int(batch[0].shape[-1]))
         finish = self._measure(steps[0].config, eng._block_times is None)
-        t0 = time.perf_counter()
-        _, st = eng.executor.run_batch(batch, steps[0].config,
-                                       slowdowns=self._slow)
-        wall = time.perf_counter() - t0
+        with span("engine.query", query=int(q0),
+                  trial=int(steps[0].serial)):
+            t0 = time.perf_counter()
+            _, st = eng.executor.run_batch(batch, steps[0].config,
+                                           slowdowns=self._slow)
+            wall = time.perf_counter() - t0
         # Stage times cover the whole batch; the per-query estimate the
         # EMA consumes is the per-query share.
         tmax = max(finish(st / n), 1e-12)
@@ -342,9 +347,11 @@ class _LiveDispatchBuilder:
         self._config = list(step.config)
         self._mesh = (list(step.mesh) if step.mesh is not None else None)
         self._S = len(self._config)
-        self._bounds = self._ex._device_bounds(self._config)
+        self._bounds = None
         self._slow = live._slow
         self._first = eng._block_times is None
+        self._trial = int(step.serial)
+        self._span = contextlib.ExitStack()     # launch to drain
         self._seq = live._width(q0)
         self._members: List[int] = []
         self._starts: List[float] = []
@@ -386,6 +393,9 @@ class _LiveDispatchBuilder:
         tokens = self._pad_rows(tokens, rows)
         self._rows = rows
         self._launched = True
+        self._span.enter_context(span("engine.query", query=self._members[0],
+                                      trial=self._trial))
+        self._bounds = self._ex._device_bounds(self._config)
         self._t0 = time.perf_counter()
         self._x, self._positions = self._ex.embed_tokens(tokens)
 
@@ -428,22 +438,26 @@ class _LiveDispatchBuilder:
         # query).  Then splice the joiner's real rows into the
         # in-flight batch and re-pad to the next warm row count.
         h, positions = ex.embed_tokens(tokens)
-        t1 = time.perf_counter()
-        h = ex._stage_fn(ex.params, h, positions,
-                         self._bounds[0][0],
-                         self._bounds[self._stage - 1][1])
-        h.block_until_ready()
+        s = self._stage
+        with span("executor.stage", stage=s - 1,
+                  blocks=int(sum(self._config[:s])), syncs=2):
+            t1 = time.perf_counter()
+            h = ex._stage_fn(ex.params, h, positions,
+                             self._bounds[0][0], self._bounds[s - 1][1])
+            h.block_until_ready()
+            fused = time.perf_counter() - t1
+            x = jnp.concatenate([self._x[:self._rows], h[:jrows]])
+            x = self._pad_rows(x, new_rows)
+            x.block_until_ready()
         if self._slow is not None:
             # Interference emulation for the fused span: stretch by the
             # mean slowdown of the stages it covers (run_stages does
             # this per stage; the fused launch can't attribute within).
-            stretch = float(np.mean(
-                np.asarray(self._slow, float)[:self._stage]))
+            stretch = float(np.mean(np.asarray(self._slow, float)[:s]))
             if stretch > 1.0:
-                time.sleep((time.perf_counter() - t1) * (stretch - 1.0))
-        x = jnp.concatenate([self._x[:self._rows], h[:jrows]])
-        x = self._pad_rows(x, new_rows)
-        x.block_until_ready()
+                with span("executor.interference", stage=s - 1,
+                          factor_pct=int(round(100 * stretch))):
+                    time.sleep(fused * (stretch - 1.0))
         self._x = x
         self._positions = jnp.broadcast_to(
             jnp.arange(self._seq, dtype=jnp.int32),
@@ -459,6 +473,7 @@ class _LiveDispatchBuilder:
             self._run_stage()
         self._ex.head(self._x)
         drain = time.perf_counter() - self._t0
+        self._span.close()
         # Per-query stage-time attribution for the EMA: each stage's
         # measured time is shared by the members present when it ran
         # (joiners' catch-up work is dispatch latency, not a per-block

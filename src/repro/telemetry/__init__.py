@@ -9,7 +9,9 @@ result types (:class:`StreamingTrace`, :class:`StreamingClusterTrace`)
 that expose the dense ``summary()`` surface at flat memory.
 
 This package imports nothing from the rest of ``repro``: the run loops
-depend on telemetry, never the reverse.
+depend on telemetry, never the reverse.  ``repro.telemetry.spans`` (host
+spans and markers on the JAX profiler's clock, docs/TELEMETRY.md
+"Spans") is imported on its own, since it needs JAX.
 """
 
 from repro.telemetry.metrics import (
