@@ -44,6 +44,53 @@ def next_pow2(n: int) -> int:
     return 1 << (max(int(n), 1) - 1).bit_length()
 
 
+def wait_ready(out: jax.Array, name: str, **meta: int) -> float:
+    """Wait for ``out`` inside span ``name`` (one host sync); returns
+    the host clock when it was seen ready."""
+    with span(name, syncs=1, **meta):
+        out.block_until_ready()
+    return time.perf_counter()
+
+
+class _ReadyClock:
+    """Stage times from the host clock at each output's readiness.
+
+    :meth:`queue` takes an output already dispatched and only then waits
+    for the one queued before it, so the host never waits with the
+    device's queue empty.  An output whose span metadata name a
+    ``stage`` is timed: the clock at its readiness less the clock at the
+    previous readiness (or at the clock's start).
+    """
+
+    def __init__(self, lo_stage: int, n: int):
+        self.times = np.zeros(n)
+        self._lo = lo_stage
+        self._pending = None
+        self._t = time.perf_counter()
+
+    def queue(self, out: jax.Array, name: str, **meta: int) -> None:
+        prev, self._pending = self._pending, (out, name, meta)
+        if prev is not None:
+            self._settle(*prev)
+
+    def drain(self) -> None:
+        """Wait for the output queued last."""
+        if self._pending is not None:
+            self._settle(*self._pending)
+            self._pending = None
+
+    def record(self, stage: int, seconds: float) -> None:
+        """A stage the caller timed itself, ending now."""
+        self.times[stage - self._lo] = seconds
+        self._t = time.perf_counter()
+
+    def _settle(self, out: jax.Array, name: str, meta: dict) -> None:
+        t = wait_ready(out, name, **meta)
+        if "stage" in meta:
+            self.times[meta["stage"] - self._lo] = t - self._t
+        self._t = t
+
+
 class LocalPipelineExecutor:
     """Executes a stage-partitioned model, timing each stage.
 
@@ -82,6 +129,11 @@ class LocalPipelineExecutor:
         self._embed_fn = embed_fn
         self._head_fn = head_fn
         self._warmed = set()       # (batch, seq) shapes already compiled
+        # Block edges 0..L as committed device scalars, once: every
+        # stage's bounds are a pair of them.
+        self._edges = [jnp.int32(i) for i in range(cfg.num_blocks + 1)]
+        jax.block_until_ready(self._edges)
+        self._pos = {}             # (batch, seq) -> positions
 
     # -- warmup ---------------------------------------------------------------
     def warmup(self, batch: int, seq: int) -> None:
@@ -119,20 +171,23 @@ class LocalPipelineExecutor:
                 self.ensure_warm(b, int(seq))
 
     # -- execution --------------------------------------------------------------
+    def _positions(self, batch: int, seq: int) -> jnp.ndarray:
+        """``[batch, seq]`` token positions, made once per shape."""
+        if (batch, seq) not in self._pos:
+            self._pos[batch, seq] = jnp.broadcast_to(
+                jnp.arange(seq, dtype=jnp.int32), (batch, seq))
+        return self._pos[batch, seq]
+
     def _device_bounds(self, config: Sequence[int]) -> List[tuple]:
         """Stage bounds as committed device scalars.
 
-        Hoisted out of the timed stage loop so the host→device transfer
-        of the ``lo``/``hi`` runtime arguments — and its jitter — never
-        lands inside a stage-time measurement the scheduler consumes.
+        Each bound is one of the block edges committed when the executor
+        was built, so no query puts or waits for a bound, and no
+        host->device transfer lands inside a stage-time measurement.
         """
-        with span("executor.bounds", syncs=2 * len(config)):
-            bounds = [(jnp.int32(lo), jnp.int32(hi))
-                      for lo, hi in stage_bounds(config)]
-            for lo, hi in bounds:
-                lo.block_until_ready()
-                hi.block_until_ready()
-        return bounds
+        with span("executor.bounds", syncs=0):
+            return [(self._edges[lo], self._edges[hi])
+                    for lo, hi in stage_bounds(config)]
 
     def embed_tokens(self, tokens: jnp.ndarray) -> tuple:
         """Embed ``[B, S]`` tokens -> (hidden ``[B, S, D]``, positions).
@@ -141,12 +196,60 @@ class LocalPipelineExecutor:
         measured time never includes the embed dispatch.
         """
         with span("executor.embed", syncs=1):
-            B, S = tokens.shape
-            positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
-                                         (B, S))
             x = self._embed_fn(self.params, tokens)
             x.block_until_ready()
-        return x, positions
+        return x, self._positions(*tokens.shape)
+
+    def _chain(self, x: jnp.ndarray, positions: jnp.ndarray,
+               config: Sequence[int], lo_stage: int, hi_stage: int,
+               slowdowns: Optional[Sequence[float]], bounds: List[tuple],
+               tokens: Optional[jnp.ndarray] = None,
+               head: bool = False) -> tuple:
+        """Run [embedding of ``tokens``], stages ``[lo_stage, hi_stage)``
+        and [the head] back to back.
+
+        Each program is dispatched before the host waits for the one
+        before it, so the device always has the next program queued and
+        no host round trip idles it.  A stage's time is the host clock
+        at its output's readiness less that at the previous output's
+        (the embedding's, or the chain's start, where ``x`` is ready).
+
+        A stage slowed by ``slowdowns`` keeps the synchronous path: it
+        is dispatched once the previous output is ready, timed from its
+        dispatch to its readiness, then the host sleeps ``(factor - 1)``
+        times that, and the next program is dispatched only after the
+        sleep, so the device idles through the emulated interference.
+        """
+        clock = _ReadyClock(lo_stage, hi_stage - lo_stage)
+        if tokens is not None:
+            with span("executor.embed", syncs=0):
+                x = self._embed_fn(self.params, tokens)
+            clock.queue(x, "executor.embed")
+        for s in range(lo_stage, hi_stage):
+            lo, hi = bounds[s]
+            meta = {"stage": s, "blocks": int(config[s])}
+            factor = 1.0 if slowdowns is None else float(slowdowns[s])
+            if factor <= 1.0:
+                with span("executor.stage", syncs=0, **meta):
+                    x = self._stage_fn(self.params, x, positions, lo, hi)
+                clock.queue(x, "executor.stage", **meta)
+                continue
+            clock.drain()
+            with span("executor.stage", syncs=1, **meta):
+                t0 = time.perf_counter()
+                x = self._stage_fn(self.params, x, positions, lo, hi)
+                x.block_until_ready()
+                dt = time.perf_counter() - t0
+            extra = dt * (factor - 1.0)
+            with span("executor.interference", stage=s,
+                      factor_pct=int(round(100 * factor))):
+                time.sleep(extra)
+            clock.record(s, dt + extra)
+        if head:
+            x = self.head(x)
+            clock.queue(x, "executor.head")
+        clock.drain()
+        return x, clock.times
 
     def run_stages(self, x: jnp.ndarray, positions: jnp.ndarray,
                    config: Sequence[int], lo_stage: int, hi_stage: int,
@@ -160,55 +263,39 @@ class LocalPipelineExecutor:
         the same jitted ``stage_fn``, since stage bounds and the batch
         dimension are runtime arguments (no recompile).
 
-        Returns ``(x, times)`` where ``times[s]`` is the measured wall
-        time of stage ``lo_stage + s`` (slowdown-stretched like
-        :meth:`run_query`).  ``bounds`` accepts the precomputed
-        :meth:`_device_bounds` result so per-stage callers don't re-pay
-        the host->device commit between boundaries.
+        ``x`` must be ready; the returned ``x`` is ready too.  Returns
+        ``(x, times)`` where ``times[s]`` is the measured wall time of
+        stage ``lo_stage + s``, timed like :meth:`run_query`'s (the
+        first from its dispatch).  ``bounds`` accepts the
+        :meth:`_device_bounds` result.
         """
         if bounds is None:
             bounds = self._device_bounds(config)
-        times = np.zeros(hi_stage - lo_stage)
-        for s in range(lo_stage, hi_stage):
-            lo, hi = bounds[s]
-            with span("executor.stage", stage=s, blocks=int(config[s]),
-                      syncs=1):
-                t0 = time.perf_counter()
-                x = self._stage_fn(self.params, x, positions, lo, hi)
-                x.block_until_ready()
-                dt = time.perf_counter() - t0
-            if slowdowns is not None and slowdowns[s] > 1.0:
-                extra = dt * (slowdowns[s] - 1.0)
-                with span("executor.interference", stage=s,
-                          factor_pct=int(round(100 * slowdowns[s]))):
-                    time.sleep(extra)
-                dt += extra
-            times[s - lo_stage] = dt
-        return x, times
+        return self._chain(x, positions, config, lo_stage, hi_stage,
+                           slowdowns, bounds)
 
     def head(self, x: jnp.ndarray) -> jnp.ndarray:
-        """Final norm + unembed, blocked until ready."""
-        with span("executor.head", syncs=1):
-            logits = self._head_fn(self.params, x)
-            logits.block_until_ready()
-        return logits
+        """Dispatch final norm + unembed; the caller waits for the
+        logits (:func:`wait_ready` with ``"executor.head"``)."""
+        with span("executor.head", syncs=0):
+            return self._head_fn(self.params, x)
 
     def run_query(self, tokens: jnp.ndarray, config: Sequence[int],
                   slowdowns: Optional[Sequence[float]] = None
                   ) -> tuple:
         """Run one query through the pipeline of ``config``.
 
-        Returns (logits, stage_times_seconds ndarray).  ``slowdowns``
-        emulates co-located interference per EP by stretching the
-        measured stage time (sleep), physically delaying the pipeline —
-        the scheduler only ever sees measured times.
+        Returns (logits, stage_times_seconds ndarray), the logits ready.
+        The embedding, every stage and the head run back to back on the
+        device (:meth:`_chain`).  ``slowdowns`` emulates co-located
+        interference per EP by stretching the measured stage time
+        (sleep), physically delaying the pipeline — the scheduler only
+        ever sees measured times.
         """
-        bounds = self._device_bounds(config)
-        x, positions = self.embed_tokens(tokens)
-        x, times = self.run_stages(x, positions, config, 0, len(config),
-                                   slowdowns=slowdowns, bounds=bounds)
-        logits = self.head(x)
-        return logits, times
+        return self._chain(None, self._positions(*tokens.shape), config,
+                           0, len(config), slowdowns,
+                           self._device_bounds(config), tokens=tokens,
+                           head=True)
 
     def run_batch(self, queries: Sequence[jnp.ndarray],
                   config: Sequence[int],
@@ -245,15 +332,10 @@ class LocalPipelineExecutor:
     def measure_block_times(self, tokens: jnp.ndarray,
                             repeats: int = 3) -> np.ndarray:
         """Per-block clean execution times (database column 0)."""
-        B, S = tokens.shape
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        positions = self._positions(*tokens.shape)
         x = self._embed_fn(self.params, tokens)
         L = self.cfg.num_blocks
-        # One committed device scalar per block boundary, outside the
-        # timed region (same hoist as run_query).
-        edges = [jnp.int32(i) for i in range(L + 1)]
-        for e in edges:
-            e.block_until_ready()
+        edges = self._edges
         times = np.zeros((repeats, L))
         for r in range(repeats):
             h = x

@@ -61,6 +61,7 @@ from repro.pipeline.executor import (
     LocalPipelineExecutor,
     MeasuredTimeSource,
     next_pow2,
+    wait_ready,
 )
 from repro.schedulers.base import SchedulerPolicy
 from repro.schedulers.defaults import DEFAULT_ALPHA, MEASURED_DETECTOR_MODE
@@ -471,7 +472,7 @@ class _LiveDispatchBuilder:
             self._launch()
         while self._stage < self._S:
             self._run_stage()
-        self._ex.head(self._x)
+        wait_ready(self._ex.head(self._x), "executor.head")
         drain = time.perf_counter() - self._t0
         self._span.close()
         # Per-query stage-time attribution for the EMA: each stage's

@@ -91,9 +91,13 @@ def test_executor_spans_nest_in_their_query_and_count_syncs(served):
                       if a <= s and e <= b]
             assert len(inside) == 1, (name, s, e)
             syncs[inside[0]] += meta.get("syncs", 0)
-    # 2 bound scalars per stage, the embedding, each stage, the head.
-    assert syncs == [3 * EPS + 2] * len(queries)
-    assert len(spans["executor.stage"]) == EPS * len(queries)
+    # The embedding, each stage, the head; the bounds are committed once
+    # per executor, so their span waits for nothing.
+    assert syncs == [EPS + 2] * len(queries)
+    assert [m for _, _, m in spans["executor.bounds"]] == \
+        [{"syncs": 0}] * len(queries)
+    waits = [m for _, _, m in spans["executor.stage"] if m["syncs"] == 1]
+    assert len(waits) == EPS * len(queries)
 
 
 def test_formed_dispatch_with_a_join(tmp_path):
@@ -131,7 +135,8 @@ def test_formed_dispatch_with_a_join(tmp_path):
             assert a <= s and e <= b, name
             syncs += m.get("syncs", 0)
     assert len(spans["executor.embed"]) == 2
-    assert syncs == 3 * EPS + 2 + 3
+    assert syncs == EPS + 2 + 3
+    assert [m for _, _, m in spans["executor.bounds"]] == [{"syncs": 0}]
     fused = [m for _, _, m in spans["executor.stage"] if m["syncs"] == 2]
     assert fused == [{"stage": 0, "blocks": 2, "syncs": 2}]
 
