@@ -1,8 +1,9 @@
 """Model / shape configuration system.
 
 Every assigned architecture is expressed as a :class:`ModelConfig`.  The
-pipeline unit is a *block* (a homogeneous super-layer) so that stage
-boundaries can be dynamic runtime arguments (see DESIGN.md §2/§4).
+pipeline unit is a *block*, one layer, so that stage boundaries can be
+dynamic runtime arguments that fall between any two layers (see
+DESIGN.md §2/§4).
 """
 from __future__ import annotations
 
@@ -53,9 +54,18 @@ class ModelConfig:
     """Architecture description.
 
     ``family`` ∈ {dense, moe, ssm, hybrid, vlm, audio}.  ``layer_pattern``
-    describes one *block* as a tuple of sublayer kinds drawn from
-    {"attn", "mamba"}; dense/moe/vlm/audio blocks are ("attn",) and the
-    Jamba block is ("attn",) + ("mamba",)*7.
+    is the period of the layers' mixer kinds, drawn from {"attn",
+    "mamba"}: layer ``i`` has kind ``layer_pattern[i % len]``.
+    dense/moe/vlm/audio have ("attn",); the Jamba period is ("attn",) +
+    ("mamba",)*7.  Every layer is one block, the pipeline unit.
+
+    The multipliers of µP-style models (Granite-4.0-H) are ``None``
+    where a model has none, and then add no operation:
+    ``embedding_multiplier`` scales the embedded tokens,
+    ``residual_multiplier`` each mixer and FFN branch before it is
+    added, and the logits are divided by ``logits_scaling``.
+    ``attention_scale`` replaces ``head_dim ** -0.5``; ``rope`` False
+    is attention without position embedding (NoPE).
     """
 
     name: str
@@ -76,7 +86,12 @@ class ModelConfig:
     sliding_window: Optional[int] = None    # None = full attention
     causal: bool = True                     # False for encoder-only (audio)
     rope_theta: float = 1e6
+    rope: bool = True
+    attention_scale: Optional[float] = None  # None = head_dim ** -0.5
     rms_eps: float = 1e-6
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
     # modality frontend stub: inputs are precomputed embeddings, not tokens
     embedding_inputs: bool = False
     # has an autoregressive decode step at all
@@ -95,19 +110,18 @@ class ModelConfig:
 
     @property
     def num_blocks(self) -> int:
-        return self.num_layers // len(self.layer_pattern)
+        """Pipeline units: one per layer."""
+        return self.num_layers
 
-    def block_has_attn(self) -> bool:
-        return "attn" in self.layer_pattern
+    def layer_kind(self, layer: int) -> str:
+        """Mixer kind of layer ``layer``: "attn" or "mamba"."""
+        return self.layer_pattern[layer % len(self.layer_pattern)]
 
-    def block_has_mamba(self) -> bool:
-        return "mamba" in self.layer_pattern
-
-    def sublayer_is_moe(self, sublayer_idx: int) -> bool:
-        """Whether the FFN of sublayer `sublayer_idx` (within a block) is MoE."""
+    def sublayer_is_moe(self, layer: int) -> bool:
+        """Whether the FFN of layer ``layer`` is MoE."""
         if self.moe is None:
             return False
-        return sublayer_idx % self.moe.every == self.moe.offset
+        return layer % self.moe.every == self.moe.offset
 
     # Rough parameter counts (used for roofline MODEL_FLOPS and reports).
     def param_count(self) -> int:
@@ -116,28 +130,25 @@ class ModelConfig:
         total = self.vocab_size * d  # embed
         if self.is_decoder:
             total += self.vocab_size * d  # unembed (untied)
-        per_pattern = 0
-        for i, kind in enumerate(self.layer_pattern):
-            if kind == "attn":
-                per_pattern += d * (n_q * h) + 2 * d * (n_kv * h) + (n_q * h) * d
+        for i in range(self.num_layers):
+            if self.layer_kind(i) == "attn":
+                total += d * (n_q * h) + 2 * d * (n_kv * h) + (n_q * h) * d
             else:  # mamba2
                 s = self.ssm
                 din = s.d_inner(d)
                 nh = s.num_heads(d)
                 # in_proj produces [z, x, B, C, dt]
-                per_pattern += d * (2 * din + 2 * s.d_state + nh) + din * d
-                per_pattern += s.d_conv * (din + 2 * s.d_state)
-            per_pattern += 2 * d  # norms
+                total += d * (2 * din + 2 * s.d_state + nh) + din * d
+                total += s.d_conv * (din + 2 * s.d_state)
+            total += 2 * d  # norms
             # FFN
             if self.moe is not None and self.sublayer_is_moe(i):
                 m = self.moe
-                per_pattern += m.num_experts * 3 * d * m.d_expert
-                per_pattern += m.num_shared_experts * 3 * d * m.d_shared
-                per_pattern += d * m.num_experts  # router
-            elif kind == "attn" and self.d_ff > 0 and (
-                    self.family not in ("ssm",)):
-                per_pattern += 3 * d * self.d_ff
-        total += self.num_blocks * per_pattern
+                total += m.num_experts * 3 * d * m.d_expert
+                total += m.num_shared_experts * 3 * d * m.d_shared
+                total += d * m.num_experts  # router
+            elif self.d_ff > 0 and self.family != "ssm":
+                total += 3 * d * self.d_ff
         return total
 
     def active_param_count(self) -> int:
@@ -147,10 +158,10 @@ class ModelConfig:
         d = self.d_model
         m = self.moe
         full = self.param_count()
-        moe_sublayers = sum(
-            1 for i in range(len(self.layer_pattern)) if self.sublayer_is_moe(i))
+        moe_layers = sum(
+            1 for i in range(self.num_layers) if self.sublayer_is_moe(i))
         inactive = (m.num_experts - m.num_experts_per_tok) * 3 * d * m.d_expert
-        return full - self.num_blocks * moe_sublayers * inactive
+        return full - moe_layers * inactive
 
 
 # ---------------------------------------------------------------------------
@@ -189,6 +200,7 @@ _ARCH_MODULES = {
     "qwen3-4b": "qwen3_4b",
     "qwen2-0.5b": "qwen2_0_5b",
     "qwen3-8b": "qwen3_8b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

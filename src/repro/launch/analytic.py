@@ -94,60 +94,60 @@ def analytic_cost(cfg: ModelConfig, shape: InputShape) -> CostBreakdown:
         act_bytes += T * d * BF16           # gather output
         param_bytes += V * d * BF16
     # ---- blocks -----------------------------------------------------------
-    for bi in range(cfg.num_blocks):
-        for i, kind in enumerate(cfg.layer_pattern):
-            if kind == "attn":
-                matmul(T, d, (nq + 2 * nkv) * h)          # qkv
-                ctx = _attn_ctx_tokens(S_ctx, cfg.sliding_window, cfg.causal) \
-                    if mode != "decode" else _attn_ctx_tokens(
-                        S_ctx, cfg.sliding_window, True)
-                if mode == "decode":
-                    ctx = (min(cfg.sliding_window, S_ctx)
-                           if cfg.sliding_window else S_ctx)
-                flops += 2.0 * T * nq * h * ctx * 2        # scores + PV
-                act_bytes += T * nq * h * BF16 * 2
-                matmul(T, nq * h, d)                       # out proj
-                if mode == "decode":
-                    # read whole cache + write one slot
-                    kv_bytes += 2 * B * S_ctx * nkv * h * BF16
-                elif mode == "prefill" and cfg.is_decoder:
-                    kv_bytes += 2 * B * S * nkv * h * BF16  # cache write
-            else:  # mamba2
-                s = cfg.ssm
-                din = s.d_inner(d)
-                H = s.num_heads(d)
-                P = s.head_dim
-                N = s.d_state
-                matmul(T, d, 2 * din + 2 * N + H)          # in_proj
-                flops += 2.0 * T * (din + 2 * N) * s.d_conv  # conv
-                if mode == "decode":
-                    # recurrent step: state update + readout
-                    flops += T * H * P * N * 4.0
-                    kv_bytes += 2 * B * H * P * N * BF16
-                else:
-                    cs = min(s.chunk_size, S)
-                    # dual form per chunk: CBᵀ + (L∘CB)X + state write/read
-                    flops += 2.0 * T * cs * N              # C·Bᵀ
-                    flops += 2.0 * T * cs * H * P          # (L∘CB)·X
-                    flops += 2.0 * T * N * H * P * 2       # states in/out
-                matmul(T, din, d)                          # out_proj
-                param_bytes += (s.d_conv * (din + 2 * N) + 3 * H) * BF16
-            # ---- FFN ------------------------------------------------------
-            if cfg.family == "ssm":
-                continue
-            if cfg.moe is not None and cfg.sublayer_is_moe(i):
-                m = cfg.moe
-                flops += 2.0 * T * d * m.num_experts       # router
-                param_bytes += d * m.num_experts * F32
-                routed_t = T * m.num_experts_per_tok * m.capacity_factor
-                flops += 2.0 * routed_t * d * m.d_expert * 3
-                act_bytes += routed_t * (2 * d + m.d_expert) * BF16
-                param_bytes += m.num_experts * 3 * d * m.d_expert * BF16
-                if m.num_shared_experts:
-                    matmul(T, d, m.num_shared_experts * m.d_shared * 3)
-            elif cfg.d_ff > 0:
-                matmul(T, d, cfg.d_ff * 3)
-            act_bytes += T * d * BF16 * 4                  # norms/residuals
+    for i in range(cfg.num_layers):
+        kind = cfg.layer_kind(i)
+        if kind == "attn":
+            matmul(T, d, (nq + 2 * nkv) * h)          # qkv
+            ctx = _attn_ctx_tokens(S_ctx, cfg.sliding_window, cfg.causal) \
+                if mode != "decode" else _attn_ctx_tokens(
+                    S_ctx, cfg.sliding_window, True)
+            if mode == "decode":
+                ctx = (min(cfg.sliding_window, S_ctx)
+                       if cfg.sliding_window else S_ctx)
+            flops += 2.0 * T * nq * h * ctx * 2        # scores + PV
+            act_bytes += T * nq * h * BF16 * 2
+            matmul(T, nq * h, d)                       # out proj
+            if mode == "decode":
+                # read whole cache + write one slot
+                kv_bytes += 2 * B * S_ctx * nkv * h * BF16
+            elif mode == "prefill" and cfg.is_decoder:
+                kv_bytes += 2 * B * S * nkv * h * BF16  # cache write
+        else:  # mamba2
+            s = cfg.ssm
+            din = s.d_inner(d)
+            H = s.num_heads(d)
+            P = s.head_dim
+            N = s.d_state
+            matmul(T, d, 2 * din + 2 * N + H)          # in_proj
+            flops += 2.0 * T * (din + 2 * N) * s.d_conv  # conv
+            if mode == "decode":
+                # recurrent step: state update + readout
+                flops += T * H * P * N * 4.0
+                kv_bytes += 2 * B * H * P * N * BF16
+            else:
+                cs = min(s.chunk_size, S)
+                # dual form per chunk: CBᵀ + (L∘CB)X + state write/read
+                flops += 2.0 * T * cs * N              # C·Bᵀ
+                flops += 2.0 * T * cs * H * P          # (L∘CB)·X
+                flops += 2.0 * T * N * H * P * 2       # states in/out
+            matmul(T, din, d)                          # out_proj
+            param_bytes += (s.d_conv * (din + 2 * N) + 3 * H) * BF16
+        # ---- FFN ------------------------------------------------------
+        if cfg.family == "ssm":
+            continue
+        if cfg.moe is not None and cfg.sublayer_is_moe(i):
+            m = cfg.moe
+            flops += 2.0 * T * d * m.num_experts       # router
+            param_bytes += d * m.num_experts * F32
+            routed_t = T * m.num_experts_per_tok * m.capacity_factor
+            flops += 2.0 * routed_t * d * m.d_expert * 3
+            act_bytes += routed_t * (2 * d + m.d_expert) * BF16
+            param_bytes += m.num_experts * 3 * d * m.d_expert * BF16
+            if m.num_shared_experts:
+                matmul(T, d, m.num_shared_experts * m.d_shared * 3)
+        elif cfg.d_ff > 0:
+            matmul(T, d, cfg.d_ff * 3)
+        act_bytes += T * d * BF16 * 4                  # norms/residuals
     # ---- head --------------------------------------------------------------
     # decoders emit last-position logits at prefill; encoders emit all
     head_t = T if (mode == "train" or not cfg.is_decoder) else B

@@ -94,7 +94,8 @@ def main() -> None:
     ap.add_argument("--eps", type=int, default=4)
     ap.add_argument("--queries", type=int, default=100)
     ap.add_argument("--blocks", type=int, default=0,
-                    help="override block count (0 = config default)")
+                    help="override the layer count, a multiple of the "
+                         "layer pattern's period (0 = config default)")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--freq", type=int, default=25,
                     help="interference frequency period (queries)")
@@ -207,8 +208,7 @@ def main() -> None:
     configure_compile_cache()
     cfg = get_smoke_config(args.arch)
     if args.blocks:
-        per = len(cfg.layer_pattern)
-        cfg = dataclasses.replace(cfg, num_layers=args.blocks * per)
+        cfg = dataclasses.replace(cfg, num_layers=args.blocks)
     params = init_params(cfg, args.seed, jnp.float32)
 
     rng = np.random.default_rng(args.seed)
@@ -291,9 +291,7 @@ def main() -> None:
             if arch not in lead:
                 c2 = get_smoke_config(arch)
                 if args.blocks:
-                    per = len(c2.layer_pattern)
-                    c2 = dataclasses.replace(c2,
-                                             num_layers=args.blocks * per)
+                    c2 = dataclasses.replace(c2, num_layers=args.blocks)
                 p2 = init_params(c2, args.seed, jnp.float32)
                 e2 = build_engine(c2, p2, lens, num_eps=args.eps,
                                   scheduler=args.scheduler,

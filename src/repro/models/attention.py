@@ -7,7 +7,8 @@ oracle for the Pallas kernel in ``repro.kernels.flash_attention``.
 
 Supports: GQA (num_kv_heads < num_heads), causal masking, sliding-window
 attention (Mixtral-style), encoder (bidirectional) mode, qk-norm (Qwen3),
-QKV bias (Qwen2).
+QKV bias (Qwen2), no position embedding and a given score scale
+(Granite-4.0-H).
 """
 from __future__ import annotations
 
@@ -51,7 +52,8 @@ def init_attention(rng, cfg: ModelConfig, dtype=jnp.float32):
 
 def _project_qkv(params, cfg: ModelConfig, x: jnp.ndarray,
                  positions: jnp.ndarray):
-    """x: [B, S, d] -> q [B,S,nq,h], k/v [B,S,nkv,h] (roped, normed)."""
+    """x: [B, S, d] -> q [B,S,nq,h], k/v [B,S,nkv,h] (normed, and roped
+    unless ``cfg.rope`` is off)."""
     B, S, _ = x.shape
     h = cfg.head_dim
     q = jnp.einsum("bsd,dk->bsk", x, params["wq"])
@@ -67,8 +69,9 @@ def _project_qkv(params, cfg: ModelConfig, x: jnp.ndarray,
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.rms_eps)
         k = rms_norm(k, params["k_norm"], cfg.rms_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -80,19 +83,22 @@ def _project_qkv(params, cfg: ModelConfig, x: jnp.ndarray,
 def flash_attention_jnp(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         *, causal: bool = True,
                         window: Optional[int] = None,
-                        chunk_q: int = 512, chunk_k: int = 512) -> jnp.ndarray:
+                        chunk_q: int = 512, chunk_k: int = 512,
+                        scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax attention.
 
     q: [B, S, Hq, D]; k, v: [B, S, Hkv, D] with Hq % Hkv == 0.
     Returns [B, S, Hq, D].  S must be divisible by the chunk sizes (the
     callers pad); masking is by absolute position (causal and/or sliding
     window of size ``window``: query i attends to keys in
-    (i - window, i]).
+    (i - window, i]).  Scores are scaled by ``scale``, by default
+    ``D ** -0.5``.
     """
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     group = Hq // Hkv
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
     nq, nk = S // chunk_q, S // chunk_k
 
     # [B, Hkv, group, nq, cq, D]
@@ -155,7 +161,8 @@ def attention_forward(params, cfg: ModelConfig, x: jnp.ndarray,
     c = _pick_chunk(S)
     out = flash_attention_jnp(q, k, v, causal=cfg.causal,
                               window=cfg.sliding_window,
-                              chunk_q=c, chunk_k=c)
+                              chunk_q=c, chunk_k=c,
+                              scale=cfg.attention_scale)
     out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"])
 
@@ -195,7 +202,8 @@ def attention_decode(params, cfg: ModelConfig, x: jnp.ndarray,
     group = Hq // Hkv
     qg = q.reshape(B, 1, Hkv, group, cfg.head_dim)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (cfg.head_dim ** -0.5)
+                   k.astype(jnp.float32)) * (
+        cfg.attention_scale or cfg.head_dim ** -0.5)
     kp = jnp.arange(S)
     mask = kp <= index
     if cfg.sliding_window is not None:

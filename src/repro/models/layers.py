@@ -74,8 +74,10 @@ def init_embedding(rng, vocab: int, d_model: int, dtype=jnp.float32):
                       * (d_model ** -0.5)).astype(dtype)}
 
 
-def embed(params, tokens: jnp.ndarray) -> jnp.ndarray:
-    return jnp.take(params["table"], tokens, axis=0)
+def embed(params, tokens: jnp.ndarray,
+          multiplier: Optional[float] = None) -> jnp.ndarray:
+    x = jnp.take(params["table"], tokens, axis=0)
+    return x if multiplier is None else x * multiplier
 
 
 def init_unembed(rng, d_model: int, vocab: int, dtype=jnp.float32):
@@ -83,8 +85,11 @@ def init_unembed(rng, d_model: int, vocab: int, dtype=jnp.float32):
                   * (d_model ** -0.5)).astype(dtype)}
 
 
-def unembed(params, x: jnp.ndarray) -> jnp.ndarray:
-    return jnp.einsum("...d,dv->...v", x, params["w"])
+def unembed(params, x: jnp.ndarray,
+            scaling: Optional[float] = None) -> jnp.ndarray:
+    """Logits of ``x``, divided by ``scaling`` where given."""
+    logits = jnp.einsum("...d,dv->...v", x, params["w"])
+    return logits if scaling is None else logits / scaling
 
 
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,

@@ -47,6 +47,12 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     A:  [H]            (negative)
     B:  [b, S, N], C: [b, S, N]  (n_groups=1, shared across heads)
     Returns (y [b, S, H, P], final_state [b, H, P, N]).
+
+    The decays (``dt * A``, its cumulative sum over the chunk, the
+    segment sums and their ``exp``) are computed in f32 whatever the
+    inputs' dtype: within a 256-token chunk the sum reaches about -100,
+    where one bf16 ulp is 0.5.  Only the decay factors that enter the
+    einsums are cast to ``x.dtype``.
     """
     b, S, H, P = x.shape
     N = B.shape[-1]
@@ -59,20 +65,21 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     Bb = B.reshape(b, nc, chunk, N)
     Cb = C.reshape(b, nc, chunk, N)
 
-    dA = dtb * A[None, None, None, :]                  # [b,nc,cs,H]
+    f32 = jnp.float32
+    dA = dtb.astype(f32) * A.astype(f32)[None, None, None, :]  # [b,nc,cs,H]
     dA = jnp.moveaxis(dA, -1, -2)                      # [b,nc,H,cs]
     dA_cs = jnp.cumsum(dA, axis=-1)                    # [b,nc,H,cs]
 
     # 1. Intra-chunk (diagonal block) output: quadratic dual form.
-    L = jnp.exp(segsum(dA))                            # [b,nc,H,cs,cs]
+    L = jnp.exp(segsum(dA)).astype(x.dtype)            # [b,nc,H,cs,cs]
     # scores: C_i · B_j
     cb = jnp.einsum("bcin,bcjn->bcij", Cb, Bb)         # [b,nc,cs,cs]
-    xdt = xb * dtb[..., None]                          # [b,nc,cs,H,P]
+    xdt = xb * dtb.astype(x.dtype)[..., None]          # [b,nc,cs,H,P]
     y_diag = jnp.einsum("bcij,bchij,bcjhp->bcihp",
                         cb, L, xdt)
 
     # 2. Chunk states: decayed sum of B ⊗ x within each chunk.
-    decay_states = jnp.exp(dA_cs[..., -1:] - dA_cs)    # [b,nc,H,cs]
+    decay_states = jnp.exp(dA_cs[..., -1:] - dA_cs).astype(x.dtype)
     states = jnp.einsum("bchl,bcln,bclhp->bchpn",
                         decay_states, Bb, xdt)         # [b,nc,H,P,N]
 
@@ -88,13 +95,13 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         return new, prev
 
     (final_state, prev_states) = jax.lax.scan(
-        scan_fn, s0.astype(jnp.float32),
-        (jnp.moveaxis(states, 1, 0).astype(jnp.float32),
-         jnp.moveaxis(chunk_decay, 1, 0).astype(jnp.float32)))
+        scan_fn, s0.astype(f32),
+        (jnp.moveaxis(states, 1, 0).astype(f32),
+         jnp.moveaxis(chunk_decay, 1, 0)))
     prev_states = jnp.moveaxis(prev_states, 0, 1)      # [b,nc,H,P,N]
 
     # 4. Inter-chunk (off-diagonal) output: read previous state.
-    state_decay = jnp.exp(dA_cs)                       # [b,nc,H,cs]
+    state_decay = jnp.exp(dA_cs).astype(x.dtype)       # [b,nc,H,cs]
     y_off = jnp.einsum("bcln,bchpn,bchl->bclhp",
                        Cb, prev_states.astype(x.dtype), state_decay)
 
@@ -108,11 +115,12 @@ def ssd_step(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     """Single-token recurrence.
 
     x: [b, H, P]; dt: [b, H]; B, C: [b, N]; state: [b, H, P, N].
-    h' = h * exp(dt A) + dt * x ⊗ B ;  y = h' · C
+    h' = h * exp(dt A) + dt * x ⊗ B ;  y = h' · C  (the decay in f32)
     """
-    dA = jnp.exp(dt * A[None, :])                      # [b,H]
-    xdt = x * dt[..., None]                            # [b,H,P]
-    new_state = (state * dA[..., None, None]
+    f32 = jnp.float32
+    dA = jnp.exp(dt.astype(f32) * A.astype(f32)[None, :])    # [b,H]
+    xdt = x * dt.astype(x.dtype)[..., None]            # [b,H,P]
+    new_state = (state * dA.astype(state.dtype)[..., None, None]
                  + jnp.einsum("bhp,bn->bhpn", xdt, B))
     y = jnp.einsum("bhpn,bn->bhp", new_state, C)
     return y, new_state
@@ -196,13 +204,12 @@ def mamba_forward(params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     xs = xBC[..., :din].reshape(B_, S, H, P)
     Bm = xBC[..., din:din + N]
     Cm = xBC[..., din + N:]
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + params["dt_bias"]).astype(x.dtype)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
     chunk = min(s.chunk_size, S)
     while S % chunk:
         chunk //= 2
-    y, _ = ssd_chunked(xs, dt, A.astype(x.dtype), Bm, Cm, chunk=chunk)
+    y, _ = ssd_chunked(xs, dt, A, Bm, Cm, chunk=chunk)
     y = y + xs * params["D"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(B_, S, din)
     y = rms_norm(y * jax.nn.silu(z), params["norm_scale"], cfg.rms_eps)
@@ -244,9 +251,8 @@ def mamba_decode(params, cfg: ModelConfig, x: jnp.ndarray, cache: dict):
     xs = xBC[..., :din].reshape(B_, H, P)
     Bm = xBC[:, 0, din:din + N]
     Cm = xBC[:, 0, din + N:]
-    dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
-                          + params["dt_bias"]).astype(x.dtype)
-    A = -jnp.exp(params["A_log"]).astype(x.dtype)
+    dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"])
+    A = -jnp.exp(params["A_log"])
     y, new_ssm = ssd_step(xs, dtv, A, Bm, Cm, cache["ssm"])
     y = y + xs * params["D"].astype(x.dtype)[None, :, None]
     y = y.reshape(B_, 1, din)

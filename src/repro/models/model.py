@@ -9,9 +9,11 @@ Public API (all functions jit-able, params are plain pytrees):
 * ``prefill(params, tokens|embeds, cache)``     -> (last logits, cache)
 * ``decode_step(params, tokens, cache, index)`` -> (logits, cache)
 
-Blocks are scanned over a stacked [num_blocks, ...] parameter pytree —
-the same representation the pipeline runtime slices per stage with
-dynamic boundaries.
+Blocks (one per layer) are scanned over a stacked [num_blocks, ...]
+parameter pytree — the same representation the pipeline runtime slices
+per stage with dynamic boundaries.  A model whose layers mix kinds
+(``blocks.is_mixed``) stacks each kind on its own and loops over layers
+with :func:`blocks.layer_forward` instead.
 """
 from __future__ import annotations
 
@@ -75,6 +77,22 @@ class Model:
         if self.remat:
             body = jax.checkpoint(body)
         stats0 = {k: jnp.zeros((), jnp.float32) for k in blk.ZERO_STATS}
+        if blk.is_mixed(cfg):
+            def layer(i, carry):
+                h, stats = carry
+                h, st = blk.layer_forward(params["blocks"], cfg, i, h,
+                                          positions)
+                return h, {k: stats[k] + st[k] for k in stats}
+
+            if self.remat:
+                layer = jax.checkpoint(layer, static_argnums=(0,)) \
+                    if self.unroll_blocks else jax.checkpoint(layer)
+            if self.unroll_blocks:
+                carry = (x, stats0)
+                for i in range(cfg.num_blocks):
+                    carry = layer(i, carry)
+                return carry
+            return jax.lax.fori_loop(0, cfg.num_blocks, layer, (x, stats0))
         if self.unroll_blocks:
             carry = (x, stats0)
             for i in range(cfg.num_blocks):
@@ -89,7 +107,7 @@ class Model:
                   embeds: Optional[jnp.ndarray]) -> jnp.ndarray:
         if embeds is not None:
             return embeds
-        return embed(params["embed"], tokens)
+        return embed(params["embed"], tokens, self.cfg.embedding_multiplier)
 
     # -- train / encoder path -----------------------------------------------
     def forward(self, params, tokens: Optional[jnp.ndarray] = None,
@@ -99,8 +117,11 @@ class Model:
         B, S, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         x, stats = self._scan_blocks(params, x, positions)
+        return self._logits(params, x), stats
+
+    def _logits(self, params, x):
         x = rms_norm(x, params["final_norm"]["scale"], self.cfg.rms_eps)
-        return unembed(params["head"], x), stats
+        return unembed(params["head"], x, self.cfg.logits_scaling)
 
     def loss(self, params, batch: Dict) -> Tuple[jnp.ndarray, Dict]:
         """batch: {tokens|embeds, labels, [mask]}."""
@@ -135,7 +156,12 @@ class Model:
 
         if self.remat:
             body = jax.checkpoint(body)
-        if self.unroll_blocks:
+        if blk.is_mixed(cfg):
+            x, new_cache = blk.mixed_cached(
+                params["blocks"], cfg, x, cache,
+                lambda kind, p, h, c: blk.mixer_prefill(
+                    kind, p, cfg, h, positions, c))
+        elif self.unroll_blocks:
             new_caches = []
             for i in range(cfg.num_blocks):
                 bp_c = jax.tree.map(lambda p: p[i], (params["blocks"], cache))
@@ -144,21 +170,25 @@ class Model:
             new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
         else:
             x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-        x = rms_norm(x[:, -1:], params["final_norm"]["scale"], cfg.rms_eps)
-        return unembed(params["head"], x), new_cache
+        return self._logits(params, x[:, -1:]), new_cache
 
     def decode_step(self, params, tokens: jnp.ndarray, cache: Dict,
                     index: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
         """tokens: [B, 1] -> (logits [B, 1, V], updated cache)."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens)
+        x = embed(params["embed"], tokens, cfg.embedding_multiplier)
 
         def body(h, bp_cache):
             bp, c = bp_cache
             h, new_c = blk.block_decode(bp, cfg, h, c, index)
             return h, new_c
 
-        if self.unroll_blocks:
+        if blk.is_mixed(cfg):
+            x, new_cache = blk.mixed_cached(
+                params["blocks"], cfg, x, cache,
+                lambda kind, p, h, c: blk.mixer_decode(
+                    kind, p, cfg, h, c, index))
+        elif self.unroll_blocks:
             new_caches = []
             for i in range(cfg.num_blocks):
                 bp_c = jax.tree.map(lambda p: p[i], (params["blocks"], cache))
@@ -167,8 +197,7 @@ class Model:
             new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
         else:
             x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
-        return unembed(params["head"], x), new_cache
+        return self._logits(params, x), new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,10 @@
 """Recompile-free pipeline-stage executor (DESIGN.md §2).
 
-The model's blocks are stacked along a leading ``[num_blocks, ...]`` axis;
-a pipeline stage executes blocks ``[lo, hi)`` via ``lax.fori_loop`` with
-*traced* bounds, so the ODIN rebalancer can move blocks between stages
+The model's blocks (one per layer) are stacked along a leading
+``[num_blocks, ...]`` axis, or by kind for a model that mixes attention
+and Mamba-2 layers (``models/blocks.py``); a pipeline stage executes
+blocks ``[lo, hi)`` via ``lax.fori_loop`` with *traced* bounds, so the
+ODIN rebalancer can move blocks between stages one layer at a time
 without triggering any recompilation — trial configurations run at full
 speed (beyond-paper: the paper processes queries serially during
 rebalancing; its exhaustive-search alternative took 42.5 minutes).
@@ -108,8 +110,8 @@ class LocalPipelineExecutor:
         @jax.jit
         def stage_fn(params, x, positions, lo, hi):
             def body(i, h):
-                bp = jax.tree.map(lambda p: p[i], params["blocks"])
-                h, _ = blk.block_forward(bp, cfg_, h, positions)
+                h, _ = blk.layer_forward(params["blocks"], cfg_, i, h,
+                                         positions)
                 return h
             with jax.named_scope("stage"):
                 return jax.lax.fori_loop(lo, hi, body, x)
@@ -117,13 +119,14 @@ class LocalPipelineExecutor:
         @jax.jit
         def embed_fn(params, tokens):
             with jax.named_scope("embed"):
-                return embed(params["embed"], tokens)
+                return embed(params["embed"], tokens,
+                             cfg_.embedding_multiplier)
 
         @jax.jit
         def head_fn(params, x):
             with jax.named_scope("head"):
                 x = rms_norm(x, params["final_norm"]["scale"], cfg_.rms_eps)
-                return unembed(params["head"], x)
+                return unembed(params["head"], x, cfg_.logits_scaling)
 
         self._stage_fn = stage_fn
         self._embed_fn = embed_fn
@@ -133,6 +136,10 @@ class LocalPipelineExecutor:
         # stage's bounds are a pair of them.
         self._edges = [jnp.int32(i) for i in range(cfg.num_blocks + 1)]
         jax.block_until_ready(self._edges)
+        # Attention layers before each edge, for the stage spans' counts.
+        self._attn_before = np.cumsum(
+            [0] + [cfg.layer_kind(i) == "attn"
+                   for i in range(cfg.num_blocks)])
         self._pos = {}             # (batch, seq) -> positions
 
     # -- warmup ---------------------------------------------------------------
@@ -225,9 +232,10 @@ class LocalPipelineExecutor:
             with span("executor.embed", syncs=0):
                 x = self._embed_fn(self.params, tokens)
             clock.queue(x, "executor.embed")
+        edges = stage_bounds(config)
         for s in range(lo_stage, hi_stage):
             lo, hi = bounds[s]
-            meta = {"stage": s, "blocks": int(config[s])}
+            meta = self._stage_meta(s, *edges[s])
             factor = 1.0 if slowdowns is None else float(slowdowns[s])
             if factor <= 1.0:
                 with span("executor.stage", syncs=0, **meta):
@@ -250,6 +258,14 @@ class LocalPipelineExecutor:
             clock.queue(x, "executor.head")
         clock.drain()
         return x, clock.times
+
+    def _stage_meta(self, stage: int, lo: int, hi: int) -> Dict[str, int]:
+        """An ``executor.stage`` span's metadata: the stage, its layer
+        range ``[lo, hi)`` and how many of its layers are attention and
+        Mamba-2."""
+        attn = int(self._attn_before[hi] - self._attn_before[lo])
+        return {"stage": stage, "blocks": hi - lo, "lo": lo, "hi": hi,
+                "attn": attn, "mamba": hi - lo - attn}
 
     def run_stages(self, x: jnp.ndarray, positions: jnp.ndarray,
                    config: Sequence[int], lo_stage: int, hi_stage: int,

@@ -70,6 +70,11 @@ def make_pipeline_fn(cfg: ModelConfig, mesh, *, stage_axis: str = "stage",
       inputs:       [M, mb, S, d] embedded microbatches (replicated)
       outputs:      [M, mb, S, d] final hidden states (replicated)
     """
+    if blk.is_mixed(cfg):
+        raise NotImplementedError(
+            f"{cfg.name}: the SPMD schedule packs one stack of blocks; a "
+            "model whose layers mix kinds is served by "
+            "LocalPipelineExecutor")
     n_stages = mesh.shape[stage_axis]
     M = num_microbatches
 
@@ -181,7 +186,8 @@ class SpmdPipelineExecutor:
         if mb * self.M > B:
             tokens = jnp.concatenate(
                 [tokens, jnp.zeros((mb * self.M - B, S), tokens.dtype)])
-        x = embed(self.params["embed"], tokens)
+        x = embed(self.params["embed"], tokens,
+                  self.cfg.embedding_multiplier)
         inputs = x.reshape(self.M, mb, S, -1)
         stage_params = pack_stage_params(self.params["blocks"], config,
                                          self.cap)
@@ -190,4 +196,4 @@ class SpmdPipelineExecutor:
         h = out.reshape(mb * self.M, S, -1)[:B]
         h = rms_norm(h, self.params["final_norm"]["scale"],
                      self.cfg.rms_eps)
-        return unembed(self.params["head"], h)
+        return unembed(self.params["head"], h, self.cfg.logits_scaling)

@@ -25,8 +25,8 @@ Accounting matches the paper's: ``num_rebalances`` counts phases that
 cost at least one serial query (the oracle is free), ``total_trials`` /
 ``mitigation_lengths`` mirror Fig. 8's exploration overhead.  Where
 those counters count, a phase's start and its commit are also marked on
-the profiler's clock (``rebalance.detect``, ``rebalance.commit``;
-docs/TELEMETRY.md "Spans").
+the profiler's clock (``rebalance.detect``, ``rebalance.commit`` with the
+layers the commit moved to another stage; docs/TELEMETRY.md "Spans").
 """
 from __future__ import annotations
 
@@ -38,6 +38,14 @@ from repro.telemetry.spans import mark
 if TYPE_CHECKING:  # annotation-only: keeps repro.core <-> schedulers acyclic
     from repro.core.pipeline_state import StageTimeSource
     from repro.schedulers.base import SchedulerPolicy
+
+
+def layers_moved(old: Sequence[int], new: Sequence[int]) -> int:
+    """Layers whose stage differs between two configurations of the
+    same layers."""
+    def stage_of(config):
+        return [s for s, n in enumerate(config) for _ in range(int(n))]
+    return sum(a != b for a, b in zip(stage_of(old), stage_of(new)))
 
 
 @dataclasses.dataclass
@@ -250,7 +258,8 @@ class RebalanceRuntime:
             self.mitigation_lengths.append(self._phase_steps)
             mark("rebalance.commit",
                  changed=int(list(res.config) != self.config),
-                 trials=self._phase_steps)
+                 trials=self._phase_steps,
+                 moved=layers_moved(self.config, res.config))
         self.explorer = None
         self._phase_steps = 0
         self.config = list(res.config)

@@ -440,8 +440,8 @@ class _LiveDispatchBuilder:
         # in-flight batch and re-pad to the next warm row count.
         h, positions = ex.embed_tokens(tokens)
         s = self._stage
-        with span("executor.stage", stage=s - 1,
-                  blocks=int(sum(self._config[:s])), syncs=2):
+        with span("executor.stage", syncs=2, **ex._stage_meta(
+                s - 1, 0, int(sum(self._config[:s])))):
             t1 = time.perf_counter()
             h = ex._stage_fn(ex.params, h, positions,
                              self._bounds[0][0], self._bounds[s - 1][1])

@@ -44,6 +44,7 @@ def test_full_config_matches_assignment(arch):
         "qwen3-4b": (36, 2560, 32, 8, 9728, 151936),
         "qwen2-0.5b": (24, 896, 14, 2, 4864, 151936),
         "qwen3-8b": (36, 4096, 32, 8, 12288, 151936),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }[arch]
     assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
             cfg.d_ff, cfg.vocab_size) == spec
@@ -53,7 +54,7 @@ def test_full_config_matches_assignment(arch):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_smoke_reduced_limits(arch):
     cfg = get_smoke_config(arch)
-    assert cfg.num_blocks <= 2
+    assert cfg.num_layers <= 2 * len(cfg.layer_pattern)
     assert cfg.d_model <= 512
     if cfg.moe is not None:
         assert cfg.moe.num_experts <= 4

@@ -138,7 +138,8 @@ def test_formed_dispatch_with_a_join(tmp_path):
     assert syncs == EPS + 2 + 3
     assert [m for _, _, m in spans["executor.bounds"]] == [{"syncs": 0}]
     fused = [m for _, _, m in spans["executor.stage"] if m["syncs"] == 2]
-    assert fused == [{"stage": 0, "blocks": 2, "syncs": 2}]
+    assert fused == [{"stage": 0, "blocks": 2, "lo": 0, "hi": 2,
+                      "attn": 2, "mamba": 0, "syncs": 2}]
 
 
 def test_interference_spans_only_on_slowed_stages(served):
@@ -163,6 +164,7 @@ def test_rebalance_marks_match_the_runtime_counters(served):
     assert len(commits) == after[1] - before[1] >= 1
     for _, _, meta in commits:
         assert meta["changed"] in (0, 1) and meta["trials"] >= 1
+        assert (meta["moved"] > 0) == bool(meta["changed"])
 
 
 def test_no_compile_marks_on_warm_shapes(served):

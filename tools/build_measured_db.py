@@ -67,7 +67,7 @@ def measure(arch: str, blocks: int, seq: int, repeats: int):
 
     cfg = get_smoke_config(arch)
     if blocks:
-        cfg = dc.replace(cfg, num_layers=blocks * len(cfg.layer_pattern))
+        cfg = dc.replace(cfg, num_layers=blocks)
     model = Model(cfg)
     params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
     ex = LocalPipelineExecutor(cfg, params)
